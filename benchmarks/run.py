@@ -9,6 +9,8 @@ import argparse
 import sys
 import time
 
+from repro import compile_cache
+
 from . import (chaos_bench, fig4_5_scalability, fig6_utilization,
                fig10_11_fps, kernel_bench, noise_ablation, overload_bench,
                sdc_bench, serve_bench, table2_vdpe_size, table3_dkv_census,
@@ -44,6 +46,7 @@ def main() -> None:
     if unknown:
         ap.error(f"unknown bench name(s) {unknown}; "
                  f"choose from {sorted(BENCHES)}")
+    compile_cache.enable()
     failures = 0
     for name, fn in BENCHES.items():
         if args.only and args.only != name:

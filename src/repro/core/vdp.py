@@ -224,6 +224,90 @@ def depthwise_conv2d_vdp(x: jax.Array, kernels: jax.Array, tpc: TPCConfig,
             jnp.concatenate(outs_r, -1).reshape(ho, wo, d))
 
 
+@partial(jax.jit, static_argnames=("k", "stride", "padding", "bits",
+                                   "depthwise"))
+def _reference_acc(x: jax.Array, w_q: jax.Array, k: int, stride: int,
+                   padding: str, bits: int, depthwise: bool,
+                   ) -> Tuple[jax.Array, jax.Array]:
+    """One layer's exact int32 accumulators and per-image DIV scales.
+
+    x (B, H, W, D); w_q (F, K*K*D), or (D, K*K) when ``depthwise`` (each
+    channel its own VDP, so its DIVs are quantized per channel too).
+    """
+    ho, wo = out_hw(x.shape[1], x.shape[2], k, stride, padding)
+
+    def one(img):
+        divs = im2col(img, k, stride, padding)                 # (P, KK*D)
+        if not depthwise:
+            q, s = quantize_symmetric(divs, bits)
+            return direct_quantized_gemm(q, w_q).reshape(ho, wo, -1), s
+        d = img.shape[-1]
+        q, s = jax.vmap(lambda c: quantize_symmetric(c, bits),
+                        in_axes=2, out_axes=(2, 0))(
+                            divs.reshape(-1, k * k, d))        # (P, KK, D)
+        acc = jnp.einsum("pkd,dk->pd", q.astype(jnp.int32),
+                         w_q.astype(jnp.int32),
+                         preferred_element_type=jnp.int32)
+        return acc.reshape(ho, wo, d), s
+
+    return jax.vmap(one)(x)
+
+
+@jax.jit
+def _dequant_bias(acc: jax.Array, scale: jax.Array,
+                  bias: jax.Array) -> jax.Array:
+    """acc * scale + bias as one fused XLA expression.
+
+    A fused epilogue is what every execution path evaluates, and a
+    backend may contract it into a fused multiply-add (XLA:CPU always
+    does), which rounds once instead of twice.  Evaluating the reference's
+    epilogue the same way keeps it comparable bit for bit.
+    """
+    return acc.astype(jnp.float32) * scale + bias
+
+
+def reference_forward(layer_defs, x: jax.Array, bits: int = 4) -> jax.Array:
+    """Plain jnp reference of an executable layer chain, per image.
+
+    ``layer_defs`` are ``engine.LayerDef``s; ``x`` is an NHWC batch.  Each
+    layer runs the paper's quantized VDP semantics with this module's
+    functions alone — ``im2col``, per-image ``quantize_symmetric`` of the
+    DIVs, per-tensor (per-channel for depthwise) ``quantize_symmetric`` of
+    the DKVs, an exact int32 contraction, the dequant scale
+    ``scale_a * scale_b``, bias and the activation — and shares no code
+    with the engine or its kernels.  The dequant scale is multiplied
+    outside any jit, so XLA cannot reassociate it with the constant 1/qmax
+    (the reason the engine pins its scales with a barrier).
+    """
+    from ..cnn.layers import ConvKind        # core.mapping's dependency
+
+    for ld in layer_defs:
+        if ld.kind is ConvKind.DC:
+            d, k, _ = ld.weights.shape
+            w_q, w_s = jax.vmap(lambda v: quantize_symmetric(v, bits))(
+                ld.weights.reshape(d, k * k))
+            acc, s = _reference_acc(x, w_q, k, ld.stride, ld.padding, bits,
+                                    True)
+            scale = (s * w_s)[:, None, None, :]
+        else:
+            k = 1 if ld.kind is ConvKind.FC else ld.weights.shape[1]
+            if ld.kind is ConvKind.FC:       # rows as 1x1 maps, K = 1
+                x = x.reshape(x.shape[0], 1, 1, -1)
+            w_q, w_s = quantize_symmetric(dkv_matrix(ld.weights), bits)
+            acc, s = _reference_acc(x, w_q, k, ld.stride, ld.padding, bits,
+                                    False)
+            scale = (s * w_s)[:, None, None, None]
+        bias = jnp.float32(0.0) if ld.bias is None else ld.bias
+        x = _dequant_bias(acc, scale, bias)
+        if ld.act == "relu":
+            x = jnp.maximum(x, 0.0)
+        elif ld.act == "relu6":
+            x = jnp.clip(x, 0.0, 6.0)
+        if ld.kind is ConvKind.FC:
+            x = x.reshape(x.shape[0], -1)
+    return x
+
+
 # ---------------------------------------------------------------------------
 # Analog noise model (Eq. 9/10) for accuracy studies
 # ---------------------------------------------------------------------------

@@ -12,13 +12,13 @@ Three execution paths, one numerics contract:
 * **Quantized-domain implicit-GEMM (default, the serving hot path).**
   ``forward`` / ``forward_layer`` route SC/PC conv layers to the
   fused-quantize implicit-GEMM kernels (kernels/vdpe_conv.py): the *raw
-  f32* NHWC activation goes to the kernel at its natural (B, Hp, Wp, D)
-  size and the entire input-DAC stage — covered-window absmax, DAC scale,
-  int8 quantize — runs in the kernel prologue off the VMEM tile, so the
-  separate XLA absmax/round/clip passes (two extra f32 reads plus an int8
-  round-trip of the activation through HBM) disappear.  The K*K patch
-  taps are gathered *inside* the kernel — the (B, P, K*K*D) im2col DIV
-  matrix never exists in HBM.  Depthwise layers run the same windowed
+  f32* NHWC activation goes to the kernel in bands of output rows, its
+  per-image DAC scale (covered-window absmax, one XLA reduction) rides
+  SMEM, and the int8 quantize runs in the kernel prologue off the VMEM
+  tile, so the separate XLA round/clip pass and the int8 round-trip of
+  the activation through HBM disappear.  The K*K patch taps are loaded
+  *inside* the kernel — the (B, P, K*K*D) im2col DIV matrix never exists
+  in HBM.  Depthwise layers run the same windowed
   gather as a per-channel integer VPU contraction in plain jnp; FC layers
   quantize in the GEMM kernels' prologues (their row absmax is a cheap
   XLA reduction, the quantize itself is fused) and stream K through the
@@ -43,19 +43,19 @@ Three execution paths, one numerics contract:
 
 Bitwise identity holds because every step matches elementwise: the
 per-image quantization scale is the max |activation| over exactly the
-patch-covered window set (the in-kernel prologue and the XLA pass both
-enumerate it through kconv.tap_window; SAME-padding zeros never raise a
-max), the quantizer rounds onto the same integer lattice through
-kernels/common.quantize_tile, integer tap-sum accumulation is associative
-(and exact in f32), and every fused epilogue applies the identical
+patch-covered window set (every path computes it through
+kconv.dac_scale; SAME-padding zeros never raise a max), the quantizer
+rounds onto the same integer lattice through
+kernels/common.quantize_tile, integer tap-sum accumulation is
+associative (and exact in f32), and every fused epilogue applies the identical
 ``act(acc * scale + bias)`` expression (kernels/common.dequant_epilogue).
 
 Batching (the serving runtime's path): all paths accept a single image
 (H, W, D) or an NHWC batch (B, H, W, D).  Quantization stays *per image*
-(each frame gets its own input-DAC swing); the conv kernels derive the
-per-image scales per grid instance, the GEMM paths carry per-row scale
-columns (kernels/vdpe_gemm.py).  For the whole-model jitted pipeline that
-chases the per-layer Python dispatch out of this loop, see
+(each frame gets its own input-DAC swing); the conv kernels read the
+per-image scales from SMEM by image grid index, the GEMM paths carry
+per-row scale columns (kernels/vdpe_gemm.py).  For the whole-model jitted
+pipeline that chases the per-layer Python dispatch out of this loop, see
 engine/pipeline.py.
 """
 from __future__ import annotations
@@ -68,7 +68,7 @@ import jax.numpy as jnp
 
 from ..cnn.layers import ConvKind
 from ..core import vdp
-from ..kernels import ops, ref
+from ..kernels import ref
 from ..kernels import vdpe_conv as kconv
 from ..kernels import vdpe_gemm as kern
 from ..kernels.common import (qmax_for, quantize_tile,
@@ -96,12 +96,6 @@ def layer_route(lp: LayerPlan) -> str:
 # Shared activation-side helpers
 # ---------------------------------------------------------------------------
 
-#: Pin a DAC scale against XLA algebraic reassociation (the PR-3
-#: reciprocal/optimization_barrier lesson) — now shared with the in-kernel
-#: quantize prologues through kernels/common.stable_scale.
-_stable_scale = stable_scale
-
-
 def _pad_spatial(x4: jax.Array, k: int, stride: int,
                  padding: str) -> jax.Array:
     """SAME/VALID spatial zero-padding, split exactly as vdp.im2col does."""
@@ -113,26 +107,6 @@ def _pad_spatial(x4: jax.Array, k: int, stride: int,
     pad_w = max((wo - 1) * stride + k - w, 0)
     return jnp.pad(x4, ((0, 0), (pad_h // 2, pad_h - pad_h // 2),
                         (pad_w // 2, pad_w - pad_w // 2), (0, 0)))
-
-
-def _window_absmax(x4p: jax.Array, k: int, stride: int, ho: int, wo: int,
-                   per_channel: bool) -> jax.Array:
-    """max |x| over the patch-covered pixel set, per image (and channel).
-
-    Identical to the im2col-matrix max: the taps enumerate exactly the
-    pixels the DIV matrix replicates (a strided layer can leave border
-    pixels uncovered, so the whole-image max would be *wrong* — the
-    covered-set max is what keeps this path bitwise-equal to the oracle).
-    The q8 conv kernels run this same tap walk in their prologues.
-    """
-    axes = (1, 2) if per_channel else (1, 2, 3)
-    m = None
-    for kk in range(k * k):
-        di, dj = divmod(kk, k)
-        win = jnp.abs(kconv.tap_window(x4p, di, dj, stride, ho, wo))
-        wm = jnp.max(win, axis=axes)
-        m = wm if m is None else jnp.maximum(m, wm)
-    return m                      # (B,) or (B, D)
 
 
 def _im2col_batch(x4: jax.Array, k: int, stride: int,
@@ -151,15 +125,15 @@ def _quantize_per_image(divs: jax.Array, bits: int,
     batch bit-identical to the per-image loop.  The oracle paths' XLA-side
     twin of the q8 kernels' fused prologue.
     """
-    scale = _stable_scale(jnp.maximum(jnp.max(jnp.abs(divs), axis=(1, 2)),
-                                      1e-12) * vdp.inv_qmax(bits))
+    scale = stable_scale(jnp.maximum(jnp.max(jnp.abs(divs), axis=(1, 2)),
+                                     1e-12) * vdp.inv_qmax(bits))
     return quantize_tile(divs, scale[:, None, None], bits), scale
 
 
 def _row_dac_scales(flat: jax.Array, bits: int) -> jax.Array:
     """Per-row DAC scales of a (B, S) stream (the q8 GEMM prologue input)."""
-    return _stable_scale(jnp.maximum(jnp.max(jnp.abs(flat), axis=1),
-                                     1e-12) * vdp.inv_qmax(bits))
+    return stable_scale(jnp.maximum(jnp.max(jnp.abs(flat), axis=1),
+                                    1e-12) * vdp.inv_qmax(bits))
 
 
 # ---------------------------------------------------------------------------
@@ -167,12 +141,13 @@ def _row_dac_scales(flat: jax.Array, bits: int) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 def _forward_conv_implicit(lp: LayerPlan, x4: jax.Array, point,
-                           interpret: bool) -> jax.Array:
+                           interpret: bool | None) -> jax.Array:
     """SC/PC layer through the fused-quantize implicit-GEMM kernels.
 
-    The raw f32 activation goes straight to the kernel; absmax, DAC scale
-    and int8 quantize all happen in the kernel prologue (no XLA passes,
-    no int8 round-trip of the activation through HBM).
+    The raw f32 activation goes straight to the kernel wrapper; its
+    per-image DAC scale is one XLA reduction (kconv.dac_scale) and the
+    int8 quantize happens in the kernel prologue (no int8 round-trip of
+    the activation through HBM).
     """
     b, h, w, din = x4.shape
     k = lp.k
@@ -210,10 +185,8 @@ def _forward_depthwise(lp: LayerPlan, x4: jax.Array, point) -> jax.Array:
     k = lp.k
     ho, wo = vdp.out_hw(h, w, k, lp.stride, lp.padding)
     x4p = _pad_spatial(x4, k, lp.stride, lp.padding)
-    a_scale = _stable_scale(
-        jnp.maximum(_window_absmax(x4p, k, lp.stride, ho, wo,
-                                   per_channel=True),
-                    1e-12) * vdp.inv_qmax(point.bits))           # (B, D)
+    a_scale = kconv.dac_scale(x4p, k, lp.stride, ho, wo, point.bits,
+                              per_channel=True)                  # (B, D)
     x_q = quantize_tile(x4p, a_scale[:, None, None, :],
                         point.bits).astype(jnp.int32)
     acc = jnp.zeros((b, ho, wo, d), jnp.int32)
@@ -243,8 +216,6 @@ def forward_layer(plan: ModelPlan, lp: LayerPlan, x: jax.Array,
     planner-compiled plans carry heterogeneous per-layer packing geometry
     while fixed-point plans repeat the model point.
     """
-    if interpret is None:
-        interpret = ops.default_interpret()
     point = lp.point
     if lp.kind is not ConvKind.FC:
         batched = x.ndim == 4
@@ -272,7 +243,7 @@ def _fc_flatten(lp: LayerPlan, x: jax.Array) -> jax.Array:
 
 
 def _forward_fc(plan: ModelPlan, lp: LayerPlan, x: jax.Array,
-                interpret: bool) -> jax.Array:
+                interpret: bool | None) -> jax.Array:
     """FC layer: (B, S) rows through the fused-quantize q8 GEMMs.
 
     The per-row DAC scales (a cheap XLA row reduction — a K-blocked GEMM
@@ -324,7 +295,7 @@ def forward(plan: ModelPlan, x: jax.Array,
 # ---------------------------------------------------------------------------
 
 def _forward_conv_implicit_f32(lp: LayerPlan, x4: jax.Array, point,
-                               interpret: bool) -> jax.Array:
+                               interpret: bool | None) -> jax.Array:
     """SC/PC float oracle: XLA quantize passes + f32 operand streams.
 
     The pre-fusion structure kept verbatim: covered-window absmax and
@@ -340,10 +311,7 @@ def _forward_conv_implicit_f32(lp: LayerPlan, x4: jax.Array, point,
                          f"got input stream of width {k * k * din}")
     ho, wo = vdp.out_hw(h, w, k, lp.stride, lp.padding)
     x4p = _pad_spatial(x4, k, lp.stride, lp.padding)
-    a_scale = _stable_scale(
-        jnp.maximum(_window_absmax(x4p, k, lp.stride, ho, wo,
-                                   per_channel=False),
-                    1e-12) * vdp.inv_qmax(point.bits))           # (B,)
+    a_scale = kconv.dac_scale(x4p, k, lp.stride, ho, wo, point.bits)  # (B,)
     x_q = quantize_tile(x4p, a_scale[:, None, None, None],
                         point.bits).astype(jnp.float32)
     rhs_f = lp.rhs.astype(jnp.float32)
@@ -370,10 +338,8 @@ def _forward_depthwise_f32(lp: LayerPlan, x4: jax.Array, point) -> jax.Array:
     k = lp.k
     ho, wo = vdp.out_hw(h, w, k, lp.stride, lp.padding)
     x4p = _pad_spatial(x4, k, lp.stride, lp.padding)
-    a_scale = _stable_scale(
-        jnp.maximum(_window_absmax(x4p, k, lp.stride, ho, wo,
-                                   per_channel=True),
-                    1e-12) * vdp.inv_qmax(point.bits))           # (B, D)
+    a_scale = kconv.dac_scale(x4p, k, lp.stride, ho, wo, point.bits,
+                              per_channel=True)                  # (B, D)
     x_q = quantize_tile(x4p, a_scale[:, None, None, :],
                         point.bits).astype(jnp.float32)
     acc = jnp.zeros((b, ho, wo, d), jnp.float32)
@@ -387,7 +353,8 @@ def _forward_depthwise_f32(lp: LayerPlan, x4: jax.Array, point) -> jax.Array:
         lp.act)
 
 
-def _forward_fc_prequantized(lp: LayerPlan, x: jax.Array, interpret: bool,
+def _forward_fc_prequantized(lp: LayerPlan, x: jax.Array,
+                             interpret: bool | None,
                              lattice_f32: bool) -> jax.Array:
     """Shared FC oracle body: XLA quantize, pre-quantized GEMM kernels.
 
@@ -429,7 +396,7 @@ def _forward_fc_prequantized(lp: LayerPlan, x: jax.Array, interpret: bool,
 
 
 def _forward_fc_f32(plan: ModelPlan, lp: LayerPlan, x: jax.Array,
-                    interpret: bool) -> jax.Array:
+                    interpret: bool | None) -> jax.Array:
     """FC float oracle: the shared body with f32 lattice streams."""
     return _forward_fc_prequantized(lp, x, interpret, lattice_f32=True)
 
@@ -442,8 +409,6 @@ def forward_layer_f32(plan: ModelPlan, lp: LayerPlan, x: jax.Array,
     the float side of the int8-vs-float kernel bench and the oracle the
     quantized-domain tests hold the int8 path against.
     """
-    if interpret is None:
-        interpret = ops.default_interpret()
     point = lp.point
     if lp.kind is ConvKind.FC:
         return _forward_fc_f32(plan, lp, x, interpret)
@@ -476,9 +441,9 @@ def _forward_depthwise_im2col(lp: LayerPlan, x4: jax.Array,
     divs = _im2col_batch(x4, k, lp.stride, lp.padding)    # (B, P, K*K*D)
     p = divs.shape[1]
     divs = divs.reshape(b, p, k * k, d)
-    a_scale = _stable_scale(jnp.maximum(jnp.max(jnp.abs(divs), axis=(1, 2)),
-                                        1e-12)
-                            * vdp.inv_qmax(point.bits))      # (B, D)
+    a_scale = stable_scale(jnp.maximum(jnp.max(jnp.abs(divs), axis=(1, 2)),
+                                       1e-12)
+                           * vdp.inv_qmax(point.bits))       # (B, D)
     divs_q = quantize_tile(divs, a_scale[:, None, None, :], point.bits)
     acc = jnp.einsum("bpkc,ck->bpc", divs_q.astype(jnp.int32),
                      lp.rhs.astype(jnp.int32))
@@ -498,10 +463,7 @@ def forward_layer_im2col(plan: ModelPlan, lp: LayerPlan, x: jax.Array,
     full (B, P, K*K*D) DIV matrix in HBM and folds the batch into one GEMM
     position stream with per-row dequant scales.
     """
-    if interpret is None:
-        interpret = ops.default_interpret()
     point = lp.point
-
     if lp.kind is ConvKind.FC:
         return _forward_fc_im2col(plan, lp, x, interpret)
     batched = x.ndim == 4
@@ -546,7 +508,7 @@ def forward_layer_im2col(plan: ModelPlan, lp: LayerPlan, x: jax.Array,
 
 
 def _forward_fc_im2col(plan: ModelPlan, lp: LayerPlan, x: jax.Array,
-                       interpret: bool) -> jax.Array:
+                       interpret: bool | None) -> jax.Array:
     """FC oracle: the shared body with int8 operand streams."""
     return _forward_fc_prequantized(lp, x, interpret, lattice_f32=False)
 
@@ -811,10 +773,8 @@ def _guarded_depthwise(lp: LayerPlan, x4: jax.Array, cargs: CorruptionArgs,
     k = lp.k
     ho, wo = vdp.out_hw(h, w, k, lp.stride, lp.padding)
     x4p = _pad_spatial(x4, k, lp.stride, lp.padding)
-    a_scale = _stable_scale(
-        jnp.maximum(_window_absmax(x4p, k, lp.stride, ho, wo,
-                                   per_channel=True),
-                    1e-12) * vdp.inv_qmax(point.bits))           # (B, D)
+    a_scale = kconv.dac_scale(x4p, k, lp.stride, ho, wo, point.bits,
+                              per_channel=True)                  # (B, D)
     x_q = quantize_tile(x4p, a_scale[:, None, None, :],
                         point.bits).astype(jnp.int32)
     rhs = lp.rhs.astype(jnp.int32)

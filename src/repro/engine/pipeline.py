@@ -13,10 +13,9 @@ implicit-GEMM conv kernels (input-DAC absmax/quantize fused into the
 kernel prologues), the depthwise VPU path, the double-buffered q8 FC
 GEMMs, fused dequant epilogues — into a single XLA program, so a served
 batch is one dispatch instead of ~L.
-Inter-layer activations are XLA temporaries (never returned to the host),
-and on accelerator backends the input batch buffer is donated to the
-computation; the CPU backend ignores donation, so it is gated off there to
-keep test logs clean.
+Inter-layer activations are XLA temporaries (never returned to the host).
+The input batch is not donated: no output has its shape, so XLA could
+never reuse its buffer.
 
 Batch sizes are bucketed to the next power of two: the dynamic batcher
 produces ragged final batches, and compiling per exact size would turn
@@ -43,7 +42,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..kernels import ops
+from ..kernels.common import resolve_interpret
 from . import executor
 from .plan import ModelPlan
 
@@ -99,8 +98,7 @@ def _build(plan: ModelPlan, interpret: bool) -> Callable:
             x = executor.forward_layer(plan, lp, x, interpret=interpret)
         return x
 
-    donate = () if jax.default_backend() == "cpu" else (1,)
-    return jax.jit(run, donate_argnums=donate)
+    return jax.jit(run)
 
 
 def get_pipeline(plan: ModelPlan, interpret: bool | None = None) -> Callable:
@@ -110,8 +108,7 @@ def get_pipeline(plan: ModelPlan, interpret: bool | None = None) -> Callable:
     a bucket traces+compiles (``pipeline_cache_info()["compiles"]`` ticks),
     every later one reuses the executable.
     """
-    if interpret is None:
-        interpret = ops.default_interpret()
+    interpret = resolve_interpret(interpret)
     with _LOCK:
         entry = _PIPELINES.get(id(plan))
         if entry is not None and entry[0] is plan:
@@ -153,13 +150,7 @@ def forward_jit(plan: ModelPlan, x: jax.Array,
         _DISPATCH_COUNTS[key] = _DISPATCH_COUNTS.get(key, 0) + 1
     if bucket != b:
         pad = [(0, bucket - b)] + [(0, 0)] * (x.ndim - 1)
-        x = jnp.pad(x, pad)                   # fresh buffer: safe to donate
-    elif jax.default_backend() != "cpu":
-        # donation consumes the argument buffer; an exact-bucket batch
-        # would hand the CALLER's array to XLA, so keep theirs alive and
-        # donate a copy instead (the pad path above already owns its
-        # buffer; the CPU backend ignores donation entirely)
-        x = jnp.array(x, copy=True)
+        x = jnp.pad(x, pad)
     out = fn(_layer_params(plan), x)
     return out[:b]
 
@@ -176,8 +167,7 @@ def _build_guarded(plan: ModelPlan,
     arrays, and baked into the traced program as Python int constants —
     the comparison point a corrupted resident imprint is caught against.
     Corruption parameters are jit *arguments* (CorruptionArgs), so one
-    executable serves clean and corrupted dispatches alike.  No donation:
-    the dispatcher may retry the same batch buffer after a detection.
+    executable serves clean and corrupted dispatches alike.
     """
     goldens = tuple(int(executor.weight_imprint_checksum(lp.rhs))
                     for lp in plan.layers)

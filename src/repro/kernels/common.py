@@ -29,9 +29,15 @@ and for the two numeric expressions every quantized-domain kernel shares:
 whole-model jit XLA's simplifier reassociates its later multiply by the
 weight scale — ``(m * c) * w -> m * (c * w)`` — shifting the epilogue
 scale by 1 ulp, which the quantizer's round() amplifies into integer
-flips.  The barrier freezes the association in eager, per-kernel-jit,
-whole-model-jit AND in-kernel-prologue regimes alike (interpret-mode
-kernel bodies are jax-traced and run through the same simplifier).
+flips.  The barrier freezes the association in eager, per-kernel-jit and
+whole-model-jit regimes alike.  It is an XLA op: Mosaic has no lowering
+for it, so every DAC scale is computed in XLA and enters the kernels as
+data; kernel bodies only divide, round and clip.
+
+``default_interpret`` is the one place that decides whether a Pallas
+kernel runs compiled (Mosaic, on a TPU backend) or in the interpreter
+(every other backend).  Every kernel wrapper's ``interpret=None``
+resolves through it.
 """
 from __future__ import annotations
 
@@ -45,6 +51,20 @@ from ..core.vdp import inv_qmax  # noqa: F401
 
 #: Fused-epilogue activations supported by every kernel in this package.
 ACTIVATIONS = ("none", "relu", "relu6")
+
+
+def default_interpret() -> bool:
+    """Interpret Pallas kernels unless JAX's default backend is a TPU.
+
+    No exception is caught: a backend that fails to initialise is an
+    error, never a silent interpret-mode run.
+    """
+    return jax.default_backend() != "tpu"
+
+
+def resolve_interpret(interpret: bool | None) -> bool:
+    """A kernel wrapper's ``interpret`` argument, ``None`` -> the default."""
+    return default_interpret() if interpret is None else interpret
 
 
 def round_up(v: int, mult: int) -> int:

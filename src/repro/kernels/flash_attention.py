@@ -21,6 +21,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .common import resolve_interpret
+
 BLOCK_Q = 128
 BLOCK_K = 128
 NEG_INF = -1e30
@@ -70,7 +72,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, causal: bool,
 def flash_attention_kernel(q: jax.Array, k: jax.Array, v: jax.Array,
                            causal: bool = True,
                            block_q: int = BLOCK_Q, block_k: int = BLOCK_K,
-                           interpret: bool = True) -> jax.Array:
+                           interpret: bool | None = None) -> jax.Array:
     """q: (BH, S, hd); k/v: (BH, T, hd) -> (BH, S, hd).
 
     S % block_q == 0 and T % block_k == 0 (ops.py pads).
@@ -91,5 +93,5 @@ def flash_attention_kernel(q: jax.Array, k: jax.Array, v: jax.Array,
         ],
         out_specs=pl.BlockSpec((1, block_q, hd), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, s, hd), q.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)

@@ -21,6 +21,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .common import resolve_interpret
+
 BLOCK_T = 128
 BLOCK_H = 128
 
@@ -38,7 +40,7 @@ def _kernel(block_to_expert, tokens_ref, w_ref, out_ref):
 def grouped_matmul_kernel(tokens: jax.Array, weights: jax.Array,
                           block_to_expert: jax.Array,
                           block_t: int = BLOCK_T, block_h: int = BLOCK_H,
-                          interpret: bool = True) -> jax.Array:
+                          interpret: bool | None = None) -> jax.Array:
     t_pad, d = tokens.shape
     e, _, h = weights.shape
     assert t_pad % block_t == 0 and h % block_h == 0
@@ -56,5 +58,5 @@ def grouped_matmul_kernel(tokens: jax.Array, weights: jax.Array,
         _kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((t_pad, h), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(block_to_expert, tokens, weights)

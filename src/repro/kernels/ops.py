@@ -23,18 +23,6 @@ from . import vdpe_gemm as k
 from .common import pad_to as _pad_to, round_up as _round_up
 
 
-def _is_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
-
-
-def default_interpret() -> bool:
-    """interpret=True everywhere except on real TPU backends."""
-    return not _is_tpu()
-
-
 def pack_mode2_weights(dkvs: jax.Array, x: int, y: int) -> jax.Array:
     """Pack (F, s<=x) small DKVs into a (y*x, F) block-diagonal matrix.
 
@@ -73,7 +61,7 @@ def pack_mode2_segments(dkvs: jax.Array, x: int) -> jax.Array:
 
 @functools.partial(jax.jit, static_argnames=("interpret", "act"))
 def mode1_gemm(divs_q: jax.Array, dkvs_q: jax.Array,
-               interpret: bool = True,
+               interpret: bool | None = None,
                scale: jax.Array | None = None,
                bias: jax.Array | None = None,
                act: str = "none") -> jax.Array:
@@ -97,7 +85,7 @@ def mode1_gemm(divs_q: jax.Array, dkvs_q: jax.Array,
 
 @functools.partial(jax.jit, static_argnames=("x", "y", "interpret", "act"))
 def mode2_gemm(divs_q: jax.Array, dkvs_q: jax.Array, x: int, y: int,
-               interpret: bool = True,
+               interpret: bool | None = None,
                scale: jax.Array | None = None,
                bias: jax.Array | None = None,
                act: str = "none") -> jax.Array:
@@ -143,8 +131,6 @@ def mixed_size_gemm(divs_q: jax.Array, dkvs_q: jax.Array,
 
     Optional fused epilogue (scale/bias/act) as in mode1_gemm/mode2_gemm.
     """
-    if interpret is None:
-        interpret = default_interpret()
     s = divs_q.shape[1]
     if s <= X_TPU:
         y = N_TPU // X_TPU
@@ -165,8 +151,6 @@ def grouped_matmul(tokens: jax.Array, weights: jax.Array,
     and unsorts.
     """
     from . import moe_gemm
-    if interpret is None:
-        interpret = default_interpret()
     t, d = tokens.shape
     e = weights.shape[0]
     order = jnp.argsort(group_ids)
@@ -207,8 +191,6 @@ def gemm_bf16(lhs: jax.Array, rhs: jax.Array,
               bias: jax.Array | None = None,
               act: str = "none") -> jax.Array:
     """Padded bf16 GEMM through the Pallas dense kernel (+fused bias/act)."""
-    if interpret is None:
-        interpret = default_interpret()
     b, s = lhs.shape
     _, o = rhs.shape
     bb, ss, oo = _round_up(b, k.BLOCK_B), _round_up(s, k.BLOCK_K), \

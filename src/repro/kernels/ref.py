@@ -14,6 +14,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .common import resolve_interpret
+
 
 def vdpe_gemm_ref(lhs: jax.Array, rhs: jax.Array) -> jax.Array:
     """Mode-1 oracle: exact int32 GEMM."""
@@ -43,7 +45,7 @@ def _pack_gemm_blockdiag_kernel(lhs_ref, rhs_ref, out_ref, *, y: int):
                                              "interpret"))
 def vdpe_pack_gemm_blockdiag(lhs: jax.Array, rhs_packed: jax.Array, y: int,
                              block_b: int = 128, block_o: int = 128,
-                             interpret: bool = True) -> jax.Array:
+                             interpret: bool | None = None) -> jax.Array:
     """The original Mode-2 Pallas kernel: (B, x) x (y*x, O) packed -> (B, O).
 
     ``rhs_packed`` is block-diagonal (ops.pack_mode2_weights): column f is
@@ -65,7 +67,7 @@ def vdpe_pack_gemm_blockdiag(lhs: jax.Array, rhs_packed: jax.Array, y: int,
         ],
         out_specs=pl.BlockSpec((block_b, block_o), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((b, o), jnp.int32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(lhs, rhs_packed)
 
 

@@ -21,11 +21,8 @@ Kernels:
   accumulator lives its whole life in registers/VMEM.
 
 * ``vdpe_pack_gemm_zs_q8`` — Mode 2, quantized-domain + zero-skipping:
-  same fused quantize prologue and epilogue, segment-sum rhs resident in
-  VMEM, and the *position stream* (the B axis — Mode 2's stream-bound
-  side, since its contraction is a single x-deep pass) double-buffered:
-  DIV block ``n+1`` is prefetched from HBM while block ``n`` rides the
-  MXU.
+  same fused quantize prologue and epilogue; the (block_b, x) DIV blocks
+  and the segment-sum rhs ride the Pallas pipeline as BlockSpec operands.
 
 * ``vdpe_gemm`` — Mode 1: K-blocked dense int8 x int8 -> int32 GEMM over
   *pre-quantized* operands (the S >= N slice path).  Also accepts f32
@@ -56,10 +53,11 @@ axis and broadcast across the O lanes.
 
 Blocked operands use explicit BlockSpec VMEM tiling with MXU-aligned block
 shapes (multiples of (32, 128) for int8 operands, (8, 128) for f32); the
-q8 kernels' streamed operands stay in HBM and ride explicit
+Mode-1 q8 kernel's K stream stays in HBM and rides explicit
 ``pltpu.make_async_copy`` DMAs into double-buffered VMEM scratch.
 Validated against kernels/ref.py in interpret mode (tests/test_kernels.py,
-tests/test_engine.py, tests/test_quantized.py).
+tests/test_engine.py, tests/test_quantized.py); the q8 kernels at
+MobileNetV1 widths also compile for a TPU v5e (tests/test_tpu_compile.py).
 """
 from __future__ import annotations
 
@@ -71,7 +69,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .common import (ACTIVATIONS, apply_act as _apply_act,  # noqa: F401
-                     dequant_epilogue as _dequant_epilogue, quantize_tile)
+                     dequant_epilogue as _dequant_epilogue, quantize_tile,
+                     resolve_interpret)
 
 # MXU-aligned default tile sizes (int8 operands tile as (32, 128) in VMEM).
 BLOCK_B = 128
@@ -133,9 +132,8 @@ def _gemm_epilogue_rows_kernel(lhs_ref, rhs_ref, scale_ref, bias_ref,
 
     The (block_b, 1) scale block is a narrow f32 block (lane dim < 128),
     the row-wise twin of the (1, block_o) bias block every epilogue here
-    already uses; Mosaic pads narrow blocks to the native tile.  Validated
-    in interpret mode (CI is CPU-only) — first real-TPU run of the batched
-    path should confirm the lowering like any other kernel change.
+    already uses; Mosaic pads narrow blocks to the native tile (the q8
+    kernels' (block_b, 1) DAC-scale blocks compile for a v5e this way).
     """
     k = pl.program_id(2)
 
@@ -155,7 +153,7 @@ def _gemm_epilogue_rows_kernel(lhs_ref, rhs_ref, scale_ref, bias_ref,
                                              "interpret", "act"))
 def vdpe_gemm(lhs: jax.Array, rhs: jax.Array,
               block_b: int = BLOCK_B, block_o: int = BLOCK_O,
-              block_k: int = BLOCK_K, interpret: bool = True,
+              block_k: int = BLOCK_K, interpret: bool | None = None,
               scale: jax.Array | None = None,
               bias: jax.Array | None = None,
               act: str = "none") -> jax.Array:
@@ -186,7 +184,7 @@ def vdpe_gemm(lhs: jax.Array, rhs: jax.Array,
             in_specs=[lhs_spec, rhs_spec],
             out_specs=out_spec,
             out_shape=jax.ShapeDtypeStruct((b, o), acc_dtype),
-            interpret=interpret,
+            interpret=resolve_interpret(interpret),
         )(lhs, rhs)
     scale = jnp.asarray(scale, jnp.float32)
     if bias is None:
@@ -207,7 +205,7 @@ def vdpe_gemm(lhs: jax.Array, rhs: jax.Array,
             out_specs=out_spec,
             out_shape=jax.ShapeDtypeStruct((b, o), jnp.float32),
             scratch_shapes=[pltpu.VMEM((block_b, block_o), acc_dtype)],
-            interpret=interpret,
+            interpret=resolve_interpret(interpret),
         )(lhs, rhs, scale.reshape(b, 1), bias)
     scale = scale.reshape(1, 1)
     return pl.pallas_call(
@@ -222,7 +220,7 @@ def vdpe_gemm(lhs: jax.Array, rhs: jax.Array,
         out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct((b, o), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_b, block_o), acc_dtype)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(scale, lhs, rhs, bias)
 
 
@@ -280,7 +278,7 @@ def _gemm_q8_kernel(w_scale_ref, lhs_hbm, rhs_hbm, a_scale_ref, bias_ref,
 def vdpe_gemm_q8(lhs: jax.Array, rhs: jax.Array, a_scale: jax.Array,
                  w_scale: jax.Array, bits: int = 4,
                  block_b: int = BLOCK_B, block_o: int = BLOCK_O,
-                 block_k: int = BLOCK_K, interpret: bool = True,
+                 block_k: int = BLOCK_K, interpret: bool | None = None,
                  bias: jax.Array | None = None,
                  act: str = "none") -> jax.Array:
     """Quantized-domain Mode-1 GEMM: (B, K) f32 x (K, O) int8 -> (B, O) f32.
@@ -314,8 +312,8 @@ def vdpe_gemm_q8(lhs: jax.Array, rhs: jax.Array, a_scale: jax.Array,
         in_specs=[
             pl.BlockSpec((1, 1), lambda i, j: (0, 0),
                          memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec((block_b, 1), lambda i, j: (i, 0)),
             pl.BlockSpec((1, block_o), lambda i, j: (0, j)),
         ],
@@ -326,7 +324,7 @@ def vdpe_gemm_q8(lhs: jax.Array, rhs: jax.Array, a_scale: jax.Array,
             pltpu.VMEM((2, block_k, block_o), jnp.int8),
             pltpu.SemaphoreType.DMA((2, 2)),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(jnp.asarray(w_scale, jnp.float32).reshape(1, 1), lhs, rhs,
       a_scale.reshape(b, 1), bias)
 
@@ -370,7 +368,7 @@ def _pack_gemm_zs_epilogue_rows_kernel(lhs_ref, rhs_ref, scale_ref, bias_ref,
                                              "interpret", "act"))
 def vdpe_pack_gemm_zs(lhs: jax.Array, rhs_seg: jax.Array,
                       block_b: int = BLOCK_B, block_o: int = BLOCK_O,
-                      interpret: bool = True,
+                      interpret: bool | None = None,
                       scale: jax.Array | None = None,
                       bias: jax.Array | None = None,
                       act: str = "none") -> jax.Array:
@@ -405,7 +403,7 @@ def vdpe_pack_gemm_zs(lhs: jax.Array, rhs_seg: jax.Array,
             in_specs=[lhs_spec, rhs_spec],
             out_specs=out_spec,
             out_shape=jax.ShapeDtypeStruct((b, o), acc_dtype),
-            interpret=interpret,
+            interpret=resolve_interpret(interpret),
         )(lhs, rhs_seg)
     scale = jnp.asarray(scale, jnp.float32)
     if bias is None:
@@ -425,7 +423,7 @@ def vdpe_pack_gemm_zs(lhs: jax.Array, rhs_seg: jax.Array,
             ],
             out_specs=out_spec,
             out_shape=jax.ShapeDtypeStruct((b, o), jnp.float32),
-            interpret=interpret,
+            interpret=resolve_interpret(interpret),
         )(lhs, rhs_seg, scale.reshape(b, 1), bias)
     scale = scale.reshape(1, 1)
     return pl.pallas_call(
@@ -439,42 +437,24 @@ def vdpe_pack_gemm_zs(lhs: jax.Array, rhs_seg: jax.Array,
         ],
         out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct((b, o), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(scale, lhs, rhs_seg, bias)
 
 
 # ---------------------------------------------------------------------------
-# Mode 2, quantized-domain: fused quantize + double-buffered DIV stream
+# Mode 2, quantized-domain: fused quantize prologue
 # ---------------------------------------------------------------------------
 
-def _pack_gemm_zs_q8_kernel(w_scale_ref, lhs_hbm, rhs_ref, a_scale_ref,
-                            bias_ref, out_ref, lhs_buf, sems, *, n_b: int,
-                            block_b: int, bits: int, act: str):
-    """Quantized-domain zero-skipping body: B-stream double-buffered.
-
-    The (x, block_o) segment-sum rhs stays resident in VMEM; DIV block
-    ``n+1`` is DMA'd from HBM into the alternate slot while block ``n``
-    is quantized and contracted (Mode 2's single x-deep pass makes the
-    position stream, not the contraction, the bound resource).
-    """
-    def copy(slot: int, n: int):
-        return pltpu.make_async_copy(
-            lhs_hbm.at[pl.ds(n * block_b, block_b), :],
-            lhs_buf.at[slot], sems.at[slot])
-
-    copy(0, 0).start()
-    rhs = rhs_ref[...]
-    w_scale = w_scale_ref[0, 0]
-    for n in range(n_b):
-        slot = n % 2
-        if n + 1 < n_b:                            # prefetch next DIV block
-            copy((n + 1) % 2, n + 1).start()
-        copy(slot, n).wait()
-        a_col = a_scale_ref[pl.ds(n * block_b, block_b), :]
-        lhs_q = quantize_tile(lhs_buf[slot], a_col, bits)
-        acc = _dot(lhs_q, rhs, jnp.int32)
-        out_ref[pl.ds(n * block_b, block_b), :] = _dequant_epilogue(
-            acc, a_col * w_scale, bias_ref[...], act)
+def _pack_gemm_zs_q8_kernel(w_scale_ref, lhs_ref, rhs_ref, a_scale_ref,
+                            bias_ref, out_ref, *, bits: int, act: str):
+    """Quantized-domain zero-skipping body: quantize, one x-deep dot,
+    epilogue.  The (block_b, x) DIV block rides the Pallas pipeline (x
+    equals the full lane dim, so the block is legal at any x <= 128)."""
+    a_col = a_scale_ref[...]                       # (block_b, 1) f32
+    lhs_q = quantize_tile(lhs_ref[...], a_col, bits)
+    acc = _dot(lhs_q, rhs_ref[...], jnp.int32)
+    out_ref[...] = _dequant_epilogue(acc, a_col * w_scale_ref[0, 0],
+                                     bias_ref[...], act)
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "block_b", "block_o",
@@ -482,7 +462,8 @@ def _pack_gemm_zs_q8_kernel(w_scale_ref, lhs_hbm, rhs_ref, a_scale_ref,
 def vdpe_pack_gemm_zs_q8(lhs: jax.Array, rhs_seg: jax.Array,
                          a_scale: jax.Array, w_scale: jax.Array,
                          bits: int = 4, block_b: int = BLOCK_B,
-                         block_o: int = BLOCK_O, interpret: bool = True,
+                         block_o: int = BLOCK_O,
+                         interpret: bool | None = None,
                          bias: jax.Array | None = None,
                          act: str = "none") -> jax.Array:
     """Quantized-domain Mode-2 GEMM: (B, x) f32 x (x, O) int8 -> (B, O) f32.
@@ -491,7 +472,10 @@ def vdpe_pack_gemm_zs_q8(lhs: jax.Array, rhs_seg: jax.Array,
     with per-row DAC scales ``a_scale``; pad rows use scale 1); ``rhs_seg``
     the dense int8 segment-sum pack with scalar dequant scale ``w_scale``.
     Bitwise-identical to quantizing outside and calling
-    ``vdpe_pack_gemm_zs`` with per-row scales.
+    ``vdpe_pack_gemm_zs`` with per-row scales.  The DIV stream is a
+    BlockSpec operand, so Pallas double-buffers it: a hand-written DMA
+    into an (x < 128)-lane scratch slot is refused by Mosaic (the slice
+    must be 128-lane aligned).
     """
     b, x = lhs.shape
     x2, o = rhs_seg.shape
@@ -505,25 +489,21 @@ def vdpe_pack_gemm_zs_q8(lhs: jax.Array, rhs_seg: jax.Array,
             f"({b}, block-padded), got shape {a_scale.shape}")
     if bias is None:
         bias = jnp.zeros((1, o), jnp.float32)
+    lhs_shape, rhs_shape, out_shape = zs_block_shapes(x, block_b, block_o)
     return pl.pallas_call(
-        functools.partial(_pack_gemm_zs_q8_kernel, n_b=b // block_b,
-                          block_b=block_b, bits=bits, act=act),
-        grid=(o // block_o,),
+        functools.partial(_pack_gemm_zs_q8_kernel, bits=bits, act=act),
+        grid=(b // block_b, o // block_o),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda j: (0, 0),
+            pl.BlockSpec((1, 1), lambda i, j: (0, 0),
                          memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec((x, block_o), lambda j: (0, j)),
-            pl.BlockSpec((b, 1), lambda j: (0, 0)),
-            pl.BlockSpec((1, block_o), lambda j: (0, j)),
+            pl.BlockSpec(lhs_shape, lambda i, j: (i, 0)),
+            pl.BlockSpec(rhs_shape, lambda i, j: (0, j)),
+            pl.BlockSpec((block_b, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((1, block_o), lambda i, j: (0, j)),
         ],
-        out_specs=pl.BlockSpec((b, block_o), lambda j: (0, j)),
+        out_specs=pl.BlockSpec(out_shape, lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((b, o), jnp.float32),
-        scratch_shapes=[
-            pltpu.VMEM((2, block_b, x), jnp.float32),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(jnp.asarray(w_scale, jnp.float32).reshape(1, 1), lhs, rhs_seg,
       a_scale.reshape(b, 1), bias)
 
@@ -561,7 +541,7 @@ def _gemm_bf16_epilogue_kernel(lhs_ref, rhs_ref, bias_ref, out_ref, acc_ref,
                                              "interpret", "act"))
 def gemm_bf16(lhs: jax.Array, rhs: jax.Array,
               block_b: int = BLOCK_B, block_o: int = BLOCK_O,
-              block_k: int = BLOCK_K, interpret: bool = True,
+              block_k: int = BLOCK_K, interpret: bool | None = None,
               bias: jax.Array | None = None,
               act: str = "none") -> jax.Array:
     """bf16 GEMM with f32 accumulation — the framework's dense tile path.
@@ -584,7 +564,7 @@ def gemm_bf16(lhs: jax.Array, rhs: jax.Array,
             in_specs=[lhs_spec, rhs_spec],
             out_specs=out_spec,
             out_shape=jax.ShapeDtypeStruct((b, o), jnp.float32),
-            interpret=interpret,
+            interpret=resolve_interpret(interpret),
         )(lhs, rhs)
     if bias is None:
         bias = jnp.zeros((1, o), jnp.float32)
@@ -598,5 +578,5 @@ def gemm_bf16(lhs: jax.Array, rhs: jax.Array,
         out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct((b, o), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_b, block_o), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(lhs, rhs, bias)

@@ -9,14 +9,9 @@ import jax
 
 
 def _mesh(shape, axes):
-    """jax.make_mesh across jax versions: ``axis_types`` (and
-    jax.sharding.AxisType) only exist on newer releases; Auto is the
-    default there, so omitting it is equivalent."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
+    """jax.make_mesh with every axis Auto-sharded."""
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -91,6 +91,60 @@ def shufflenet_mini(seed: int = 0) -> List[LayerDef]:
     ]
 
 
+def _folded_conv(rng: np.random.Generator, shape: Tuple[int, ...],
+                 fan_in: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """He-initialised conv weights with a random inference BatchNorm
+    folded in: w * gamma / sqrt(var + eps), bias beta - mean * that."""
+    w = rng.normal(size=shape) * np.sqrt(2.0 / fan_in)
+    c = shape[0]
+    gamma = 1.0 + 0.1 * rng.normal(size=c)
+    beta = 0.1 * rng.normal(size=c)
+    mean = 0.1 * rng.normal(size=c)
+    g = gamma / np.sqrt(rng.uniform(0.5, 1.5, size=c) + 1e-3)
+    w = w * g.reshape((c,) + (1,) * (len(shape) - 1))
+    return (jnp.asarray(w, jnp.float32),
+            jnp.asarray(beta - mean * g, jnp.float32))
+
+
+def mobilenet_v1_defs(seed: int = 0, resolution: int = 224,
+                      ) -> List[LayerDef]:
+    """MobileNetV1 1.0 at full width as an executable chain.
+
+    Derived from the ``cnn/models.py`` census (``mobilenet_v1``): conv1,
+    13 depthwise/pointwise pairs and the 1024 -> 1000 FC, every conv with
+    BN folded and relu6.  The strides are the census' own spatial walk at
+    224; other resolutions keep them.  The census leaves out the global
+    average pool (no tensor product); here it is a VALID depthwise layer
+    over the whole final map with constant 1/(h*w) weights, which the
+    per-channel weight quantizer maps exactly onto qmax.  Not in
+    ``SERVING_MODELS``: the CPU tests iterate that table in interpret mode.
+    """
+    rng = np.random.default_rng((seed, 0x3B1))
+    defs: List[LayerDef] = []
+    h, h_census = resolution, 224
+    for spec in MODEL_ZOO["mobilenet_v1"]():
+        if spec.kind is ConvKind.FC:
+            defs.append(LayerDef(
+                "avg_pool", ConvKind.DC,
+                jnp.full((spec.d, h, h), 1.0 / (h * h), jnp.float32),
+                padding="VALID"))
+            w = rng.normal(size=(spec.f, spec.d)) / np.sqrt(spec.d)
+            defs.append(LayerDef(spec.name, ConvKind.FC,
+                                 jnp.asarray(w, jnp.float32),
+                                 bias=_b(rng, spec.f)))
+            continue
+        stride = h_census // spec.h_out
+        h_census, h = spec.h_out, -(-h // stride)
+        k = spec.k
+        if spec.kind is ConvKind.DC:
+            w, b = _folded_conv(rng, (spec.f, k, k), k * k)
+        else:
+            w, b = _folded_conv(rng, (spec.f, k, k, spec.d), k * k * spec.d)
+        defs.append(LayerDef(spec.name, spec.kind, w, bias=b, act="relu6",
+                             stride=stride))
+    return defs
+
+
 #: name -> (weight factory, input shape HWC, paper-scale simulator table)
 SERVING_MODELS: Dict[str, Tuple[Callable[[int], List[LayerDef]],
                                 Tuple[int, int, int], str]] = {

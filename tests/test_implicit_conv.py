@@ -394,3 +394,128 @@ def test_forward_jit_fc_row_batches():
     got = engine.forward_jit(plan, xb, interpret=True)
     want = engine.forward(plan, xb, interpret=True)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+# ---------------------------------------------------------------------------
+# Banded, phase-split kernels: hoisted DAC scale, strides, uneven bands
+# ---------------------------------------------------------------------------
+
+# the kernels' fused epilogue is one XLA expression (which XLA:CPU
+# contracts into an FMA); the oracle's epilogue is evaluated the same way
+_jit_epilogue = jax.jit(ref.epilogue_ref, static_argnames="act")
+
+
+@pytest.mark.parametrize("mode2", [False, True])
+@pytest.mark.parametrize("k,stride,h,w,rows", [
+    (1, 1, 9, 8, None), (1, 1, 9, 8, 2), (3, 1, 9, 8, 4), (3, 1, 9, 8, None),
+    (1, 2, 9, 16, 2), (3, 2, 9, 16, 2), (3, 2, 9, 16, None)])
+def test_banded_conv_q8_matches_oracles(mode2, k, stride, h, w, rows):
+    """The q8 kernels — DAC scale computed in XLA and read from SMEM,
+    stride taps from phase planes, output rows tiled into bands that need
+    not divide H — == the im2col oracle and the f32-lattice kernel."""
+    rng = np.random.default_rng(100 * k + 10 * stride + (rows or 0))
+    b, d = 2, 3
+    x4 = jnp.asarray(rng.normal(size=(b, h, w, d)) * 3.0, jnp.float32)
+    x4p = ex._pad_spatial(x4, k, stride, "SAME")
+    ho, wo = vdp.out_hw(h, w, k, stride, "SAME")
+    if rows is not None:
+        assert ho % rows != 0                     # the last band is partial
+    s = k * k * d
+    rhs = _rand_int8(rng, (ops.X_TPU if mode2 else common.round_up(s, 128),
+                           128))
+    w_scale = jnp.float32(0.021)
+    bias = jnp.asarray(rng.normal(size=(1, 128)), jnp.float32)
+    if rows is None:                              # the public entry points
+        if mode2:
+            got = kconv.vdpe_pack_conv_zs_q8(
+                x4p, rhs, w_scale, k, stride, ho, wo, x=ops.X_TPU,
+                bias=bias, act="relu6", interpret=True)
+        else:
+            got = kconv.vdpe_conv_q8(x4p, rhs, w_scale, k, stride, ho, wo,
+                                     bias=bias, act="relu6", interpret=True)
+    else:                                         # their body, banded
+        got = kconv._conv_q8(x4p, rhs, w_scale, k, stride, ho, wo, 4, 128,
+                             True, bias, "relu6", rows=rows)
+    a_scale = kconv.dac_scale(x4p, k, stride, ho, wo, 4)
+    x_q = common.quantize_tile(x4p, a_scale[:, None, None, None], 4)
+    acc = jax.lax.dot_general(
+        _im2col_int(x_q, k, stride, ho, wo).astype(jnp.int32),
+        rhs[:s].astype(jnp.int32), (((2,), (0,)), ((), ())),
+        preferred_element_type=jnp.int32)
+    want = _jit_epilogue(acc, (a_scale * w_scale)[:, None, None], bias[None],
+                         act="relu6")
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    lattice = kconv.vdpe_conv(x_q.astype(jnp.float32),
+                              rhs.astype(jnp.float32), k, stride, ho, wo,
+                              scale=a_scale * w_scale, bias=bias,
+                              act="relu6", interpret=True)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(lattice))
+
+
+@pytest.mark.parametrize("stride", [1, 2, 3])
+def test_phase_planes_hold_strided_pixels(stride):
+    """Plane p*s + q is x[:, p::s, q::s], cropped or zero-padded."""
+    x = jnp.arange(2 * 10 * 11 * 3, dtype=jnp.float32).reshape(2, 10, 11, 3)
+    rows, cols = 5, 4
+    planes = kconv._phase_planes(x, stride, rows, cols)
+    assert planes.shape == (2, stride * stride, rows, cols, 3)
+    for p in range(stride):
+        for q in range(stride):
+            sub = x[:, p::stride, q::stride][:, :rows, :cols]
+            want = jnp.pad(sub, ((0, 0), (0, rows - sub.shape[1]),
+                                 (0, cols - sub.shape[2]), (0, 0)))
+            np.testing.assert_array_equal(
+                np.asarray(planes[:, p * stride + q]), np.asarray(want))
+
+
+@pytest.mark.parametrize("ho,wo,d,stride,k", [
+    (112, 112, 3, 2, 3), (112, 112, 32, 1, 1), (56, 56, 64, 1, 1),
+    (28, 28, 256, 1, 1), (14, 14, 512, 1, 1), (7, 7, 1024, 1, 1)])
+def test_band_rows_fit_vmem_budget(ho, wo, d, stride, k):
+    """MobileNetV1's SC/PC layers get sublane-aligned bands whose padded
+    input + output blocks fit one pipeline buffer's budget."""
+    rows = kconv.band_rows(ho, wo, d, stride, k)
+    assert 1 <= rows <= ho
+    assert rows == ho or (rows * wo) % 8 == 0
+    halo = (k - 1) // stride
+    in_bytes = (stride * stride * (rows + halo) * common.round_up(wo + halo, 8)
+                * common.round_up(d, 128) * 4)
+    assert in_bytes + rows * wo * 128 * 4 <= kconv.VMEM_BAND_BYTES
+
+
+# ---------------------------------------------------------------------------
+# MobileNetV1 1.0 as an executable chain
+# ---------------------------------------------------------------------------
+
+def test_mobilenet_v1_defs_follow_the_census():
+    """Full-width defs at 224 walk the census' shapes, plus the pool."""
+    from repro.cnn.models import MODEL_ZOO
+    defs = zoo.mobilenet_v1_defs(0)
+    specs = engine.defs_to_specs(defs, (224, 224, 3))
+    census = MODEL_ZOO["mobilenet_v1"]()
+    pool = [sp for sp in specs if sp.name == "avg_pool"]
+    assert [sp.canonical() for sp in specs if sp.name != "avg_pool"] == \
+        [sp.canonical() for sp in census]
+    assert pool[0].k == 7 and pool[0].f == 1024 and pool[0].h_out == 1
+    assert all(ld.act == "relu6" for ld in defs
+               if ld.name not in ("avg_pool", "predictions"))
+
+
+def test_mobilenet_v1_chain_matches_plain_reference():
+    """At 32x32 the chain hits all four routes, and the whole-model jitted
+    pipeline equals core.vdp.reference_forward bit for bit."""
+    defs = zoo.mobilenet_v1_defs(0, resolution=32)
+    plan = engine.compile_model("mobilenet_v1_32", defs)
+    routes = {lp.name: engine.layer_route(lp) for lp in plan.layers}
+    assert len(routes) == 29
+    assert routes["conv1"] == routes["pw1"] == ex.ROUTE_CONV_ZS
+    assert all(routes[f"pw{i}"] == ex.ROUTE_CONV_M1 for i in range(2, 14))
+    assert all(routes[f"dw{i}"] == ex.ROUTE_DEPTHWISE for i in range(1, 14))
+    assert routes["avg_pool"] == ex.ROUTE_DEPTHWISE
+    assert routes["predictions"] == ex.ROUTE_FC_GEMM
+    x = jnp.asarray(np.random.default_rng(0).normal(size=(2, 32, 32, 3)),
+                    jnp.float32)
+    got = engine.forward_jit(plan, x, interpret=True)
+    want = vdp.reference_forward(defs, x)
+    assert got.shape == (2, 1000)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

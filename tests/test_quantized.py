@@ -102,9 +102,7 @@ def test_conv_q8_matches_prequantized(k, stride):
     bias = jnp.asarray(rng.normal(size=(1, 128)), jnp.float32)
     got = kconv.vdpe_conv_q8(x4p, rhs, w_scale, k, stride, ho, wo,
                              bits=4, interpret=True, bias=bias, act="relu")
-    a_scale = ex._stable_scale(
-        jnp.maximum(ex._window_absmax(x4p, k, stride, ho, wo, False),
-                    1e-12) * common.inv_qmax(4))
+    a_scale = kconv.dac_scale(x4p, k, stride, ho, wo, 4)
     x_q = jnp.clip(jnp.round(x4p / a_scale[:, None, None, None]),
                    -7, 7).astype(jnp.int8)
     want = kconv.vdpe_conv(x_q, rhs, k, stride, ho, wo, interpret=True,
