@@ -1,0 +1,8 @@
+"""Host-to-device transfer time per served batch in the online cells: the
+program's ``h2d`` span (``CNNServer.step``'s per-request transfers,
+inside ``stack``), mean in ms (moves ``p50_ms``)."""
+import spans
+
+
+def value(run):
+    return spans.mean_ms(run, "h2d")

@@ -105,8 +105,9 @@ def _pad_spatial(x4: jax.Array, k: int, stride: int,
     ho, wo = vdp.out_hw(h, w, k, stride, padding)
     pad_h = max((ho - 1) * stride + k - h, 0)
     pad_w = max((wo - 1) * stride + k - w, 0)
-    return jnp.pad(x4, ((0, 0), (pad_h // 2, pad_h - pad_h // 2),
-                        (pad_w // 2, pad_w - pad_w // 2), (0, 0)))
+    with jax.named_scope("pad"):
+        return jnp.pad(x4, ((0, 0), (pad_h // 2, pad_h - pad_h // 2),
+                            (pad_w // 2, pad_w - pad_w // 2), (0, 0)))
 
 
 def _im2col_batch(x4: jax.Array, k: int, stride: int,
@@ -132,8 +133,9 @@ def _quantize_per_image(divs: jax.Array, bits: int,
 
 def _row_dac_scales(flat: jax.Array, bits: int) -> jax.Array:
     """Per-row DAC scales of a (B, S) stream (the q8 GEMM prologue input)."""
-    return stable_scale(jnp.maximum(jnp.max(jnp.abs(flat), axis=1),
-                                    1e-12) * vdp.inv_qmax(bits))
+    with jax.named_scope("dac_scale"):
+        return stable_scale(jnp.maximum(jnp.max(jnp.abs(flat), axis=1),
+                                        1e-12) * vdp.inv_qmax(bits))
 
 
 # ---------------------------------------------------------------------------
@@ -168,7 +170,8 @@ def _forward_conv_implicit(lp: LayerPlan, x4: jax.Array, point,
             x4p, lp.rhs, lp.w_scale, k, lp.stride, ho, wo,
             bits=point.bits, block_o=point.block_o, interpret=interpret,
             bias=lp.bias, act=lp.act)
-    return out[:, :, :lp.f].reshape(b, ho, wo, lp.f)
+    with jax.named_scope("out"):
+        return out[:, :, :lp.f].reshape(b, ho, wo, lp.f)
 
 
 def _forward_depthwise(lp: LayerPlan, x4: jax.Array, point) -> jax.Array:
@@ -187,17 +190,19 @@ def _forward_depthwise(lp: LayerPlan, x4: jax.Array, point) -> jax.Array:
     x4p = _pad_spatial(x4, k, lp.stride, lp.padding)
     a_scale = kconv.dac_scale(x4p, k, lp.stride, ho, wo, point.bits,
                               per_channel=True)                  # (B, D)
-    x_q = quantize_tile(x4p, a_scale[:, None, None, :],
-                        point.bits).astype(jnp.int32)
-    acc = jnp.zeros((b, ho, wo, d), jnp.int32)
-    for kk in range(k * k):
-        di, dj = divmod(kk, k)
-        win = kconv.tap_window(x_q, di, dj, lp.stride, ho, wo)
-        acc = acc + win * lp.rhs[:, kk].astype(jnp.int32)[None, None, None]
-    return ref.epilogue_ref(
-        acc, (a_scale * lp.w_scale[None, :])[:, None, None, :],
-        None if lp.bias is None else lp.bias[None, None, None, :],
-        lp.act)
+    with jax.named_scope("depthwise"):
+        x_q = quantize_tile(x4p, a_scale[:, None, None, :],
+                            point.bits).astype(jnp.int32)
+        acc = jnp.zeros((b, ho, wo, d), jnp.int32)
+        for kk in range(k * k):
+            di, dj = divmod(kk, k)
+            win = kconv.tap_window(x_q, di, dj, lp.stride, ho, wo)
+            acc = acc + win * lp.rhs[:, kk].astype(jnp.int32)[None, None,
+                                                              None]
+        return ref.epilogue_ref(
+            acc, (a_scale * lp.w_scale[None, :])[:, None, None, :],
+            None if lp.bias is None else lp.bias[None, None, None, :],
+            lp.act)
 
 
 def forward_layer(plan: ModelPlan, lp: LayerPlan, x: jax.Array,
@@ -273,7 +278,8 @@ def _forward_fc(plan: ModelPlan, lp: LayerPlan, x: jax.Array,
             block_b=point.block_b, block_o=point.block_o,
             block_k=point.block_k, interpret=interpret,
             bias=lp.bias, act=lp.act)
-    return out[:b, :lp.f]                 # FC single image stays (1, F)
+    with jax.named_scope("out"):
+        return out[:b, :lp.f]             # FC single image stays (1, F)
 
 
 def forward(plan: ModelPlan, x: jax.Array,

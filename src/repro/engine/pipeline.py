@@ -88,14 +88,26 @@ def _layer_params(plan: ModelPlan) -> tuple:
     return tuple((lp.rhs, lp.w_scale, lp.bias) for lp in plan.layers)
 
 
+def layer_scope(i: int, lp) -> str:
+    """The named scope of layer ``i`` in the pipeline: ``L03_pw1_<route>``.
+    Inside it the stages are scoped too (``pad``, ``dac_scale``,
+    ``phase_planes``, ``depthwise``, ``out``), so each device op of a
+    profile carries its layer and stage in its ``op_name``."""
+    return f"L{i:02d}_{lp.name}_{executor.layer_route(lp)}"
+
+
 def _build(plan: ModelPlan, interpret: bool) -> Callable:
     def run(params, xb):
         _STATS["compiles"] += 1   # trace-time side effect: counts retraces
         x = xb
-        for lp, (rhs, w_scale, bias) in zip(plan.layers, params):
+        for i, (lp, (rhs, w_scale, bias)) in enumerate(zip(plan.layers,
+                                                           params)):
             lp = dataclasses.replace(lp, rhs=rhs, w_scale=w_scale,
                                      bias=bias)
-            x = executor.forward_layer(plan, lp, x, interpret=interpret)
+            # names the layer's device ops in a profile (HLO metadata
+            # only: the compiled arithmetic is unchanged)
+            with jax.named_scope(layer_scope(i, lp)):
+                x = executor.forward_layer(plan, lp, x, interpret=interpret)
         return x
 
     return jax.jit(run)

@@ -135,8 +135,9 @@ def dac_scale(x4p: jax.Array, k: int, stride: int, ho: int, wo: int,
     Every path — the q8 kernels' wrappers, the float and im2col oracles,
     the depthwise and guarded paths — computes its scale here.
     """
-    m = window_absmax(x4p, k, stride, ho, wo, per_channel)
-    return stable_scale(jnp.maximum(m, 1e-12) * inv_qmax(bits))
+    with jax.named_scope("dac_scale"):
+        m = window_absmax(x4p, k, stride, ho, wo, per_channel)
+        return stable_scale(jnp.maximum(m, 1e-12) * inv_qmax(bits))
 
 
 def band_rows(ho: int, wo: int, d: int, stride: int, k: int,
@@ -172,11 +173,12 @@ def _phase_planes(x: jax.Array, stride: int, rows: int,
     """
     b, hp, wp, d = x.shape
     s = stride
-    x = x[:, :rows * s, :cols * s]
-    x = jnp.pad(x, ((0, 0), (0, rows * s - x.shape[1]),
-                    (0, cols * s - x.shape[2]), (0, 0)))
-    x = x.reshape(b, rows, s, cols, s, d).transpose(0, 2, 4, 1, 3, 5)
-    return x.reshape(b, s * s, rows, cols, d)
+    with jax.named_scope("phase_planes"):
+        x = x[:, :rows * s, :cols * s]
+        x = jnp.pad(x, ((0, 0), (0, rows * s - x.shape[1]),
+                        (0, cols * s - x.shape[2]), (0, 0)))
+        x = x.reshape(b, rows, s, cols, s, d).transpose(0, 2, 4, 1, 3, 5)
+        return x.reshape(b, s * s, rows, cols, d)
 
 
 def _conv_kernel(*refs, k, stride, bh, wo, d, bits, act, epilogue):

@@ -18,6 +18,10 @@ structured attributes.  Design constraints, in order:
    the *modeled photonic hardware* duration from ``core/simulator``, which
    :mod:`repro.obs.export` lays out on a second Perfetto process so host
    overhead and cycle-true device occupancy sit side by side.
+4. **One clock with the device.**  An enabled, kept span is also a
+   ``jax.profiler.TraceAnnotation`` carrying its scalar args, so a
+   profile taken with a host tracer level of 1 or more holds it on the
+   host plane, on the same clock as the device's operations.
 
 Span nesting is tracked per thread: a span opened inside another becomes
 its child (``parent_id``); worker-thread spans are roots on their own
@@ -31,6 +35,8 @@ import threading
 import time
 from collections import deque
 from typing import Any, Callable, Dict, Iterable, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 
 @dataclasses.dataclass(frozen=True)
@@ -120,7 +126,7 @@ class _Span:
     """Live span handle produced by :meth:`Tracer.span` (context manager)."""
 
     __slots__ = ("_tr", "name", "cat", "tid", "args", "t0", "span_id",
-                 "parent_id", "hw_instance", "hw_s", "_sampled")
+                 "parent_id", "hw_instance", "hw_s", "_sampled", "_ann")
 
     def __init__(self, tr: "Tracer", name: str, cat: str,
                  tid: Optional[str], args: Dict[str, Any], sampled: bool):
@@ -152,6 +158,8 @@ class _Span:
             stack = tr._stack()
             self.parent_id = stack[-1] if stack else None
             stack.append(self.span_id)
+            self._ann = TraceAnnotation(self.name)
+            self._ann.__enter__()
         self.t0 = tr._time()
         return self
 
@@ -165,6 +173,10 @@ class _Span:
             stack.pop()
         if exc_type is not None:
             self.args["error"] = exc_type.__name__
+        # args set inside the body are known only now
+        self._ann.set_metadata(**{k: v for k, v in self.args.items()
+                                  if isinstance(v, (int, float, str))})
+        self._ann.__exit__(None, None, None)
         if self.tid is None:
             self.tid = threading.current_thread().name
         tr._emit(SpanRecord(

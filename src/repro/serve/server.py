@@ -100,6 +100,20 @@ class ServeSLO:
                 f"{self.corruption_halflife_s}")
 
 
+def _timed_transfers(requests, span) -> list:
+    """The batch's images to the device one by one, each transfer timed:
+    the traced path of ``CNNServer.step``'s ``h2d`` span, whose counters
+    are ``transfers``, ``bytes`` and ``max_ms`` (the slowest transfer)."""
+    xs, slowest = [], 0.0
+    for r in requests:
+        t = time.perf_counter()
+        xs.append(jnp.asarray(r.x, jnp.float32))
+        slowest = max(slowest, time.perf_counter() - t)
+    span.set(transfers=len(xs), bytes=sum(x.nbytes for x in xs),
+             max_ms=1e3 * slowest)
+    return xs
+
+
 class CNNServer:
     def __init__(self, registry: PlanRegistry, max_batch: int = 8,
                  max_wait_s: float = 0.005,
@@ -463,23 +477,34 @@ class CNNServer:
         if fb is None:
             return 0
         tr = self.tracer
+        waits = fb.queue_waits()
+        bucket = engine.batch_bucket(fb.size)
         with tr.span("batch", cat="batch", model=fb.model, size=fb.size,
-                     bucket=engine.batch_bucket(fb.size)) as bsp:
+                     bucket=bucket) as bsp:
+            if tr.enabled:
+                bsp.set(queue_wait_s=sum(waits) / fb.size)
             t0 = time.perf_counter()
             with tr.span("plan.fetch", cat="batch", model=fb.model):
                 entry = self.registry.get(fb.model)
             with tr.span("stack", cat="batch"):
-                xb = jnp.stack([jnp.asarray(r.x, jnp.float32)
-                                for r in fb.requests])
+                with tr.span("h2d", cat="batch") as hsp:
+                    if tr.enabled:
+                        xs = _timed_transfers(fb.requests, hsp)
+                    else:
+                        xs = [jnp.asarray(r.x, jnp.float32)
+                              for r in fb.requests]
+                xb = jnp.stack(xs)
             compiles_before = engine.pipeline_cache_info()["compiles"]
             sdc_before = (self.dispatcher.counters["sdc_detections"]
                           if self.dispatcher is not None else 0)
             shard_info = ()
             with tr.span("exec", cat="batch", model=fb.model):
                 if self.dispatcher is None:
-                    out = engine.forward_jit(entry.plan, xb,
-                                             interpret=self.interpret)
-                    out = jax.block_until_ready(out)
+                    with tr.span("dispatch", cat="batch", bucket=bucket):
+                        out = engine.forward_jit(entry.plan, xb,
+                                                 interpret=self.interpret)
+                    with tr.span("device_wait", cat="batch"):
+                        out = jax.block_until_ready(out)
                 else:
                     # shard the batch across the fleet; outputs keep
                     # request order (sim_specs lets a hardware-paced fleet
@@ -533,7 +558,8 @@ class CNNServer:
                 self.telemetry.record_sdc(fb.model, detections,
                                           corrupted_frames)
             with tr.span("epilogue", cat="batch"):
-                out_np = np.asarray(out)
+                with tr.span("d2h", cat="batch", bytes=out.nbytes):
+                    out_np = np.asarray(out)
                 lats = []
                 for i, req in enumerate(fb.requests):
                     self.results[req.rid] = out_np[i]
@@ -541,23 +567,25 @@ class CNNServer:
                     lats.append(lat)
                     tr.async_end("request", aid=req.rid, model=fb.model,
                                  latency_s=lat)
-                self.telemetry.record_batch(
-                    model=fb.model, sim_specs=entry.sim_specs,
-                    batch_size=fb.size, t_formed=now, exec_s=exec_s,
-                    queue_waits_s=fb.queue_waits(), latencies_s=lats,
-                    shards=shard_info, exec_specs=entry.exec_specs,
-                    op_points=entry.plan.layer_points,
-                    reconfig_switches=entry.plan.reconfig_switches,
-                    priorities=fb.priorities())
+                with tr.span("telemetry", cat="batch"):
+                    self.telemetry.record_batch(
+                        model=fb.model, sim_specs=entry.sim_specs,
+                        batch_size=fb.size, t_formed=now, exec_s=exec_s,
+                        queue_waits_s=waits, latencies_s=lats,
+                        shards=shard_info, exec_specs=entry.exec_specs,
+                        op_points=entry.plan.layer_points,
+                        reconfig_switches=entry.plan.reconfig_switches,
+                        priorities=fb.priorities())
+                    if tr.enabled and self.dispatcher is None:
+                        # unsharded: the whole batch's modeled device time
+                        # lands on one "local" hardware track (sharded
+                        # batches annotate per-shard hardware time in the
+                        # dispatcher instead)
+                        cost = self.telemetry._hw_cost(
+                            fb.model, entry.sim_specs, fb.size,
+                            self.telemetry.points[0])
+                        bsp.hw("local", cost.frame_latency_s * fb.size)
             bsp.set(compiles=compiled, exec_s=exec_s)
-            if self.dispatcher is None:
-                # unsharded: the whole batch's modeled device time lands
-                # on one "local" hardware track (sharded batches annotate
-                # per-shard hardware time in the dispatcher instead)
-                primary = self.telemetry.points[0]
-                cost = self.telemetry._hw_cost(
-                    fb.model, entry.sim_specs, fb.size, primary)
-                bsp.hw("local", cost.frame_latency_s * fb.size)
         return fb.size
 
     def run_until_drained(self, max_steps: int = 100_000,
