@@ -2,7 +2,8 @@
 
 One `step()` forms at most one batch (dynamic batcher policy), fetches the
 model's resident plan (registry, LRU), stacks the requests into an NHWC
-batch, runs it through the whole-model jitted pipeline
+batch (on the host and sent in one transfer, unless a request's image is
+already a device array), runs it through the whole-model jitted pipeline
 (engine.forward_jit) — the entire layer chain against the resident DKV
 imprint in ONE XLA dispatch — and splits the outputs back to their
 requests.  With a ``dispatcher`` (serve/dispatch.py) the batch is instead
@@ -100,18 +101,24 @@ class ServeSLO:
                 f"{self.corruption_halflife_s}")
 
 
-def _timed_transfers(requests, span) -> list:
-    """The batch's images to the device one by one, each transfer timed:
-    the traced path of ``CNNServer.step``'s ``h2d`` span, whose counters
-    are ``transfers``, ``bytes`` and ``max_ms`` (the slowest transfer)."""
-    xs, slowest = [], 0.0
-    for r in requests:
+def _timed_transfers(xs, put, span) -> list:
+    """``put`` of each of ``xs``, each call timed: the traced path of
+    ``CNNServer.step``'s ``h2d`` span, whose counters are ``transfers``,
+    ``bytes`` and ``max_ms`` (the slowest transfer).  ``xs`` is the
+    host-stacked batch alone (one transfer) or, when a request's image is
+    already a device array, the requests' images one by one."""
+    out, slowest = [], 0.0
+    for x in xs:
         t = time.perf_counter()
-        xs.append(jnp.asarray(r.x, jnp.float32))
+        out.append(put(x))
         slowest = max(slowest, time.perf_counter() - t)
-    span.set(transfers=len(xs), bytes=sum(x.nbytes for x in xs),
+    span.set(transfers=len(out), bytes=sum(x.nbytes for x in out),
              max_ms=1e3 * slowest)
-    return xs
+    return out
+
+
+def _as_f32(x) -> jax.Array:
+    return jnp.asarray(x, jnp.float32)
 
 
 class CNNServer:
@@ -486,14 +493,25 @@ class CNNServer:
             t0 = time.perf_counter()
             with tr.span("plan.fetch", cat="batch", model=fb.model):
                 entry = self.registry.get(fb.model)
-            with tr.span("stack", cat="batch"):
-                with tr.span("h2d", cat="batch") as hsp:
+            with tr.span("stack", cat="batch") as ssp:
+                xs = [r.x for r in fb.requests]
+                # host images are stacked on the host and sent in one
+                # transfer; images already on the device stay there, as
+                # pulling them back would add a device-to-host copy
+                on_host = not any(isinstance(x, jax.Array) for x in xs)
+                ssp.set(host_stacked=int(on_host))
+                if on_host:
+                    with tr.span("host_stack", cat="batch") as hsp:
+                        xs = [np.stack([np.asarray(x, np.float32)
+                                        for x in xs])]
+                        hsp.set(bytes=xs[0].nbytes)
+                put = jax.device_put if on_host else _as_f32
+                with tr.span("h2d", cat="batch") as dsp:
                     if tr.enabled:
-                        xs = _timed_transfers(fb.requests, hsp)
+                        xs = _timed_transfers(xs, put, dsp)
                     else:
-                        xs = [jnp.asarray(r.x, jnp.float32)
-                              for r in fb.requests]
-                xb = jnp.stack(xs)
+                        xs = [put(x) for x in xs]
+                xb = xs[0] if on_host else jnp.stack(xs)
             compiles_before = engine.pipeline_cache_info()["compiles"]
             sdc_before = (self.dispatcher.counters["sdc_detections"]
                           if self.dispatcher is not None else 0)

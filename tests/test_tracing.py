@@ -1,8 +1,10 @@
 """The serving step's phases on the profiler's clock: the sub-spans of
 ``CNNServer.step`` and their counters, the ``Tracer``'s
 ``jax.profiler.TraceAnnotation`` mirror (on a CPU profile's host plane),
-the free path with tracing off, and the pipeline's named scopes per layer
-and stage in the compiled HLO."""
+the free path with tracing off, the batch's stack (on the host and in one
+transfer for host images, on the device when a request's image is already
+there, the same bits either way), and the pipeline's named scopes per
+layer and stage in the compiled HLO."""
 import glob
 import os
 import re
@@ -22,7 +24,8 @@ jax.config.update("jax_platform_name", "cpu")
 
 SHAPE = (8, 8, 3)
 #: span -> its parent in one unsharded ``CNNServer.step``
-PARENT = {"plan.fetch": "batch", "stack": "batch", "h2d": "stack",
+PARENT = {"plan.fetch": "batch", "stack": "batch", "host_stack": "stack",
+          "h2d": "stack",
           "exec": "batch", "dispatch": "exec", "device_wait": "exec",
           "epilogue": "batch", "d2h": "epilogue", "telemetry": "epilogue"}
 
@@ -53,6 +56,23 @@ def _registry():
 def _images(n, seed=0):
     return np.random.default_rng(seed).normal(
         size=(n,) + SHAPE).astype(np.float32)
+
+
+def _host_images(kind, n):
+    """``n`` host images of one kind: f32 views into one buffer at
+    unaligned, overlapping offsets (as the benchmark's generator cuts
+    them), or whole f64 or uint8 arrays."""
+    size = int(np.prod(SHAPE))
+    rng = np.random.default_rng(3)
+    if kind == "f32_views":
+        buf = rng.uniform(-1.0, 1.0, 7 * n + size).astype(np.float32)
+        return [buf[7 * i:7 * i + size].reshape(SHAPE) for i in range(n)]
+    if kind == "f64":
+        return list(rng.normal(size=(n,) + SHAPE))
+    return list(rng.integers(0, 256, size=(n,) + SHAPE, dtype=np.uint8))
+
+
+HOST_KINDS = ["f32_views", "f64", "u8"]
 
 
 def _serve_one_batch(srv, n=4, t_submit=(1.0, 1.5, 2.0, 2.5), now=3.0):
@@ -90,17 +110,63 @@ def test_traced_step_records_the_phases_under_their_parents():
     assert by_name["batch"].hw_s > 0
 
 
+@pytest.mark.parametrize("kind", HOST_KINDS)
 @pytest.mark.parametrize("n", [1, 3])
-def test_h2d_counts_the_transfers_and_their_bytes(n):
+def test_h2d_counts_the_transfers_and_their_bytes(n, kind):
     srv, tr = _traced_server()
-    xs = _images(n)
+    xs = _host_images(kind, n)
     for x in xs:
         srv.submit("micro", x, now=0.0)
     assert srv.step(now=0.0, force=True) == n
-    (h2d,) = [s for s in tr.events() if s.name == "h2d"]
-    assert h2d.args["transfers"] == n
-    assert h2d.args["bytes"] == sum(x.nbytes for x in xs)
+    by_name = {s.name: s for s in tr.events() if s.ph == "X"}
+    h2d, host = by_name["h2d"], by_name["host_stack"]
+    # host images: stacked on the host, then one transfer of the batch's
+    # f32 bytes, whatever the images' own dtype
+    f32_bytes = n * int(np.prod(SHAPE)) * 4
+    assert by_name["stack"].args["host_stacked"] == 1
+    assert host.parent_id == by_name["stack"].span_id
+    assert host.args["bytes"] == f32_bytes
+    assert h2d.args["transfers"] == 1
+    assert h2d.args["bytes"] == f32_bytes
     assert 0 <= h2d.args["max_ms"] <= 1e3 * h2d.dur
+
+
+@pytest.mark.parametrize("kind", HOST_KINDS)
+@pytest.mark.parametrize("n", [1, 3, 4])
+def test_a_host_stacked_batch_serves_the_bits_of_the_forward_pass(n, kind):
+    srv = serve.CNNServer(_registry(), max_batch=4, max_wait_s=0.0,
+                          interpret=True)
+    xs = _host_images(kind, n)
+    rids = [srv.submit("micro", x, now=0.0) for x in xs]
+    assert srv.step(now=0.0, force=True) == n
+    entry = srv.registry.get("micro")
+    want = engine.forward(entry.plan, jnp.asarray(np.stack(xs), jnp.float32),
+                          interpret=True)
+    np.testing.assert_array_equal(
+        np.stack([srv.results[r] for r in rids]), np.asarray(want))
+
+
+@pytest.mark.parametrize("on_device", [(0, 1, 2), (1,)],
+                         ids=["every_image", "one_image"])
+def test_a_batch_holding_a_device_array_is_stacked_on_the_device(on_device):
+    srv, tr = _traced_server()
+    xs = _images(3)
+    rids = [srv.submit("micro", jnp.asarray(x) if i in on_device else x,
+                       now=0.0) for i, x in enumerate(xs)]
+    assert srv.step(now=0.0, force=True) == 3
+    by_name = {s.name: s for s in tr.events() if s.ph == "X"}
+    assert "host_stack" not in by_name
+    assert by_name["stack"].args["host_stacked"] == 0
+    assert by_name["h2d"].args["transfers"] == 3
+    assert by_name["h2d"].args["bytes"] == xs.nbytes
+    # the same bits as the host path's
+    host_srv = serve.CNNServer(_registry(), max_batch=4, max_wait_s=0.0,
+                               interpret=True)
+    host_rids = [host_srv.submit("micro", x, now=0.0) for x in xs]
+    assert host_srv.step(now=0.0, force=True) == 3
+    np.testing.assert_array_equal(
+        np.stack([srv.results[r] for r in rids]),
+        np.stack([host_srv.results[r] for r in host_rids]))
 
 
 def _host_events(trace_dir):
@@ -139,7 +205,9 @@ def test_spans_land_on_the_profiles_host_plane_with_their_nesting(tmp_path):
         (p0, p1, _), = host[parent]
         assert p0 <= c0 and c1 <= p1, (child, parent)
     (_, _, h2d), = host["h2d"]
-    assert h2d["transfers"] == 4 and h2d["bytes"] == 4 * 8 * 8 * 3 * 4
+    assert h2d["transfers"] == 1 and h2d["bytes"] == 4 * 8 * 8 * 3 * 4
+    (_, _, stack), = host["stack"]
+    assert stack["host_stacked"] == 1
     (_, _, batch), = host["batch"]
     assert batch["size"] == 4 and batch["model"] == "micro"
     assert batch["queue_wait_s"] == pytest.approx(1.0)
