@@ -3,8 +3,10 @@
 Everything a cell is made of is found by name: its workload file under
 ``workloads/`` names a configuration (``configs/<name>.json``) and a
 traffic mix (``traffic/<name>.json``) and may override the mix's
-numbers; the metrics it reports are the entries of ``BENCHMARK.json``
-that apply to it, each computed by ``metrics/<name>.py``.
+numbers; the configuration may name its own model and reference modules
+(``config_modules``); the metrics it reports are the entries of
+``BENCHMARK.json`` that apply to it, each computed by
+``metrics/<name>.py``.
 
 The timed path is the user's entry: ``CNNServer.submit`` for every
 request, then ``CNNServer.step``, which runs the whole-model jitted
@@ -20,9 +22,11 @@ import heapq
 import importlib.util
 import json
 import math
+import re
 import sys
 import time
 from pathlib import Path
+from types import ModuleType
 from typing import Callable, Dict, List, Optional
 
 import numpy as np
@@ -50,6 +54,8 @@ class Layout:
     workloads: Path = BENCH_DIR / "workloads"
     metrics: Path = BENCH_DIR / "metrics"
     peaks: Path = BENCH_DIR / "peaks.json"
+    #: where a configuration's ``model`` and ``reference`` paths resolve
+    modules: Path = BENCH_DIR
 
 
 def _read_json(path: Path) -> dict:
@@ -81,16 +87,87 @@ def cell_metrics(bench: dict, cell: str, traced: bool) -> List[dict]:
                 else m["moves"] in moves)]
 
 
+def _load_module(path: Path) -> ModuleType:
+    """The module in the file ``path``, run once per process."""
+    name = "bench_" + re.sub(r"\W", "_", str(path.resolve()))
+    mod = sys.modules.get(name)
+    if mod is None:
+        spec = importlib.util.spec_from_file_location(name, path)
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        sys.modules[name] = mod
+    return mod
+
+
 def load_reducer(layout: Layout, name: str) -> Callable:
     """``metrics/<name>.py``'s ``value(run) -> float | None``."""
     path = layout.metrics / f"{name}.py"
     if not path.is_file():
         raise FileNotFoundError(f"metric {name!r} has no reader {path}")
-    spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{name.replace('.', '_')}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.value
+    return _load_module(path).value
+
+
+#: what a configuration's model module and reference module define
+MODEL_API = ("make_weights", "program", "geoms", "ops_per_image")
+REFERENCE_API = ("forward",)
+#: the modules of a configuration that names none: the layer chain
+DEFAULT_MODULES = {"model": BENCH_DIR / "model.py",
+                   "reference": BENCH_DIR / "reference.py"}
+
+
+@dataclasses.dataclass(frozen=True)
+class ConfigModules:
+    """A configuration's model module and reference module."""
+    model: ModuleType
+    reference: ModuleType
+
+
+def module_path(layout: Layout, cfg: dict, key: str) -> Path:
+    """The file of ``cfg``'s ``key`` module (``model`` or ``reference``):
+    ``cfg[key]`` under ``layout.modules``, else the default."""
+    return layout.modules / cfg[key] if key in cfg else DEFAULT_MODULES[key]
+
+
+def config_modules(layout: Layout, cfg: dict) -> ConfigModules:
+    """The model and reference modules of a configuration.
+
+    A configuration file may name them under ``"model"`` and
+    ``"reference"``, as paths relative to ``layout.modules`` (the bench
+    directory); where it names neither, they are ``model.py`` (the layer
+    chain of its ``layers`` table, with ``opcount``'s arithmetic) and
+    ``reference.py``.  The model module defines
+
+    - ``make_weights(cfg, seed)``: the weight pytree, made on the device
+      from the seed;
+    - ``program(cfg, weights)``: the factory that
+      ``serve.PlanRegistry.register`` takes;
+    - ``geoms(cfg)``: the ``opcount.Geom`` of each layer whose kernel
+      ``pallas_roofline`` reads;
+    - ``ops_per_image(cfg)``: the operations one image needs, which
+      ``mfu`` divides by;
+
+    and the reference module ``forward(cfg, weights, images, bits)``:
+    the logits of ``images`` (N, H, W, C), computed at ``bits``, with
+    nothing imported from the program.  The check that compares the
+    served logits with them is ``reference.logit_gap`` for every
+    configuration.  A module that is missing, or lacks one of these
+    names, fails here, naming the configuration and what is missing.
+    """
+    found = {}
+    for key, api in (("model", MODEL_API), ("reference", REFERENCE_API)):
+        path = module_path(layout, cfg, key)
+        if not path.is_file():
+            raise FileNotFoundError(
+                f"configuration {cfg['name']!r}: its {key} module {path} "
+                f"does not exist")
+        mod = _load_module(path)
+        missing = [n for n in api if not callable(getattr(mod, n, None))]
+        if missing:
+            raise AttributeError(
+                f"configuration {cfg['name']!r}: its {key} module {path} "
+                f"lacks {', '.join(missing)}")
+        found[key] = mod
+    return ConfigModules(**found)
 
 
 def device_peaks(layout: Layout, device_kind: str) -> dict:
@@ -335,7 +412,8 @@ class Served:
     """A cell's server, warmed and ready for its window."""
     cell: dict
     cfg: dict
-    weights: list
+    modules: ConfigModules
+    weights: object
     registry: object
     server: object
     tracer: object
@@ -350,7 +428,8 @@ class Served:
 
 def set_up(cell_name: str, seed: int, traced: bool, layout: Layout,
            point_bits: Optional[int] = None) -> Served:
-    """Weights from the seed, the plan, the server, and a warm-up of the
+    """Weights from the seed and the program, both from the configuration's
+    model module, the plan, the server, and a warm-up of the
     batch sizes the cell's traffic uses (twice: the first compiles or
     loads from the cache, the second finds everything warm).
 
@@ -363,8 +442,6 @@ def set_up(cell_name: str, seed: int, traced: bool, layout: Layout,
     from repro import compile_cache, engine, serve
     from repro.obs.tracer import Tracer
 
-    import model
-
     compile_cache.enable()
     # cache every program, however quick its compile, so a run's set-up
     # finds all of them after the first run
@@ -372,15 +449,15 @@ def set_up(cell_name: str, seed: int, traced: bool, layout: Layout,
     cell = load_cell(layout, cell_name)
     p = cell["params"]
     cfg = opcount.load_config(cell["config"], layout.configs)
+    mods = config_modules(layout, cfg)
     peaks = device_peaks(layout, jax.devices()[0].device_kind)
     counter = _CompileCounter()
     bits = cfg["quantization_bits"] if point_bits is None else point_bits
-    weights = jax.block_until_ready(model.make_weights(cfg, seed))
-    defs = model.layer_defs(cfg, weights)
+    weights = jax.block_until_ready(mods.model.make_weights(cfg, seed))
     shape = tuple(cfg["input_shape"])
     registry = serve.PlanRegistry(
         capacity=1, point=engine.EnginePoint(bits=bits))
-    registry.register(cfg["name"], lambda: defs, shape)
+    registry.register(cfg["name"], mods.model.program(cfg, weights), shape)
     tracer = Tracer(capacity=1 << 21) if traced else None
     server = serve.CNNServer(registry, max_batch=p["max_batch"],
                              max_wait_s=p["max_wait_s"], tracer=tracer,
@@ -398,7 +475,7 @@ def set_up(cell_name: str, seed: int, traced: bool, layout: Layout,
     # run: frozen, a full collection in the window does not walk it again
     gc.collect()
     gc.freeze()
-    return Served(cell, cfg, weights, registry, server, tracer,
+    return Served(cell, cfg, mods, weights, registry, server, tracer,
                   generator.Images(shape, seed), peaks, counter)
 
 
@@ -425,7 +502,7 @@ def run(cell_name: str, seed: int, seconds: float, traced: bool,
     server, registry, tracer, images = (sv.server, sv.registry, sv.tracer,
                                         sv.images)
     name, counter, dev = sv.model, sv.compiles, jax.devices()[0]
-    weights = sv.weights
+    weights, mods = sv.weights, sv.modules
     setup_s = time.perf_counter() - t_process_start
     say(f"set-up: {setup_s:.3f} s (warmed batch sizes "
         f"{p['warm_batch_sizes']})")
@@ -484,7 +561,8 @@ def run(cell_name: str, seed: int, seconds: float, traced: bool,
         t_end=t_end, due=np.asarray(req.due),
         started=np.asarray(req.started), done=done,
         counted=np.arange(len(done)) < attempted, batches=req.batches,
-        geoms=opcount.walk(cfg), ops_per_image=opcount.ops_per_image(cfg),
+        geoms=mods.model.geoms(cfg),
+        ops_per_image=mods.model.ops_per_image(cfg),
         peaks=sv.peaks, spans=spans)
     unanswered = int(np.sum(np.isnan(done[:attempted])))
 
@@ -497,9 +575,9 @@ def run(cell_name: str, seed: int, seconds: float, traced: bool,
     t0 = time.perf_counter()
     gap = math.inf
     if sample:
-        want = reference.forward(cfg, weights,
-                                 images.batch([i for i, _ in sample]),
-                                 cfg["quantization_bits"])
+        want = mods.reference.forward(cfg, weights,
+                                      images.batch([i for i, _ in sample]),
+                                      cfg["quantization_bits"])
         gap = reference.logit_gap(np.stack([o for _, o in sample]), want)
     limit = cell["check"]["logit_gap_limit"]
     say(f"reference: {len(sample)} requests in "

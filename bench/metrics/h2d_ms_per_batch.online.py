@@ -1,5 +1,5 @@
 """Host-to-device transfer time per served batch in the online cells: the
-program's ``h2d`` span (``CNNServer.step``'s per-request transfers,
+program's ``h2d`` span (the batch's one transfer in ``CNNServer.step``,
 inside ``stack``), mean in ms (moves ``p50_ms``)."""
 import spans
 

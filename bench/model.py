@@ -1,14 +1,18 @@
 """Weights of a configuration from a seed, and the program's layer chain.
 
-The benchmark makes the weights itself, on the device, in one jitted
-call, so the reference can take the same arrays without taking anything
-the program made.  ``layer_defs`` hands them to the program as its
-``LayerDef`` chain; the pool is the program's own convention, a VALID
-depthwise layer with constant weights 1/(h*w).
+The model module of every configuration that names none of its own
+(``harness.config_modules`` states the contract): the chain of the
+configuration's ``layers`` table, with ``opcount``'s shapes and
+operations.  The benchmark makes the weights itself, on the device, in
+one jitted call, so the reference can take the same arrays without
+taking anything the program made.  ``layer_defs`` hands them to the
+program as its ``LayerDef`` chain; the pool is the program's own
+convention, a VALID depthwise layer with constant weights 1/(h*w).
 """
 from __future__ import annotations
 
 from functools import partial
+from typing import Callable
 
 import jax
 import jax.numpy as jnp
@@ -82,3 +86,14 @@ def layer_defs(cfg: dict, weights: list) -> list:
         defs.append(LayerDef(g.name, kind, w, bias=b, act=g.act,
                              stride=g.stride, padding=g.padding))
     return defs
+
+
+def program(cfg: dict, weights: list) -> Callable[[], list]:
+    """The factory ``serve.PlanRegistry.register`` takes: the chain,
+    built once."""
+    defs = layer_defs(cfg, weights)
+    return lambda: defs
+
+
+geoms = opcount.walk
+ops_per_image = opcount.ops_per_image

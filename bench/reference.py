@@ -1,6 +1,11 @@
-"""Plain reference of a configuration's quantized forward pass.
+"""Plain reference of a configuration's quantized forward pass, and the
+output check.
 
-It follows the configuration file and the paper's quantized VDP
+``forward`` is the reference of every configuration that names no
+reference module of its own (``harness.config_modules``); ``logit_gap``
+compares the served logits with any configuration's reference.
+
+``forward`` follows the configuration file and the paper's quantized VDP
 semantics, in ``jax.numpy`` alone, and imports nothing of the program:
 each layer gathers its K x K patches (the DIVs), quantizes them per image
 (per image and channel for depthwise and the pool) to ``bits`` signed

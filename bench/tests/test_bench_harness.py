@@ -3,12 +3,14 @@ the check's failures: the control and faults planted under the timed path.
 
 ``harness.run`` is what ``run.py`` calls once it has found a TPU; these
 tests call it directly on the CPU (Pallas in interpret mode), against a
-tiny configuration with MobileNetV1's layer kinds under ``data/``.
+tiny configuration with MobileNetV1's layer kinds under ``data/``, and
+against one that brings its own model and reference modules.
 """
 import json
 import time
 from pathlib import Path
 
+import jax.numpy as jnp
 import pytest
 
 import devtrace
@@ -17,7 +19,8 @@ import harness
 DATA = Path(__file__).parent / "data"
 LAYOUT = harness.Layout(benchmark=DATA / "benchmark.json",
                         configs=DATA / "configs", traffic=DATA / "traffic",
-                        workloads=DATA / "workloads", peaks=DATA / "peaks.json")
+                        workloads=DATA / "workloads", peaks=DATA / "peaks.json",
+                        modules=DATA)
 SEED = 2 ** 40 + 3
 
 
@@ -41,6 +44,8 @@ def _well_formed(line, names):
 @pytest.mark.parametrize("cell, names", [
     ("tiny_bulk", {"img_per_s", "setup_s"}),
     ("tiny_online", {"p50_ms", "setup_s"}),
+    # a configuration with its own modules, and no edit to the harness
+    ("tiny_chain_bulk", {"img_per_s", "setup_s"}),
 ])
 def test_untraced_run_ends_in_a_well_formed_correct_line(cell, names):
     line = _run(cell)
@@ -95,6 +100,18 @@ def test_a_fault_under_the_timed_path_fails_the_check(monkeypatch, fault):
     monkeypatch.setattr(engine, "forward_jit",
                         lambda plan, x, **kw: fault(real(plan, x, **kw)))
     line = _run("tiny_bulk")
+    assert line["correct"] is False
+    assert line["checks"]["logit_gap"]["value"] > \
+        line["checks"]["logit_gap"]["limit"]
+
+
+def test_the_configurations_own_reference_decides_its_check(monkeypatch):
+    cfg = json.loads((DATA / "configs" / "tiny_chain.json").read_text())
+    ref = harness.config_modules(LAYOUT, cfg).reference
+    real = ref.forward
+    monkeypatch.setattr(ref, "forward",
+                        lambda *a: _altered(jnp.asarray(real(*a))))
+    line = _run("tiny_chain_bulk")
     assert line["correct"] is False
     assert line["checks"]["logit_gap"]["value"] > \
         line["checks"]["logit_gap"]["limit"]

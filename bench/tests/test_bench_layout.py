@@ -49,7 +49,9 @@ def test_cell_files_name_what_exists(cell):
     c = harness.load_cell(LAYOUT, cell)
     assert (c["config"], c["traffic"]) == (w["config"], w["traffic"])
     cfg = next(x for x in BENCH["configs"] if x["name"] == w["config"])
-    assert json.loads((ROOT / cfg["file"]).read_text())["name"] == cfg["name"]
+    cfg_file = json.loads((ROOT / cfg["file"]).read_text())
+    assert cfg_file["name"] == cfg["name"]
+    harness.config_modules(LAYOUT, cfg_file)     # its modules load whole
     p = c["params"]
     assert p["loop"] in ("closed", "open")
     assert p["max_batch"] in p["warm_batch_sizes"]
